@@ -209,10 +209,17 @@ class SecureGallery:
         """Decrypt-once match-time view of shard ``s`` for ``dtype``:
         pre-normalized rows, plus the int8 values/scales for the quantized
         path.  This is the enrollment-side half of the fused kernel entry
-        (queries are normalized in-kernel; the gallery is normalized here)."""
+        (queries are normalized in-kernel; the gallery is normalized here).
+
+        The view lives on device ``s mod device_count``: one shard per
+        chip, so each shard's scan runs where its rows are (on a
+        one-chip host, every shard shares the chip)."""
         prep = self._prep[s]
         if "gn" not in prep:
-            g = jnp.asarray(decrypt_array(self._cipher_key, self._shards[s]))
+            devices = jax.devices()
+            g = jax.device_put(decrypt_array(self._cipher_key,
+                                             self._shards[s]),
+                               devices[s % len(devices)])
             prep["gn"] = g / jnp.maximum(
                 jnp.linalg.norm(g, axis=-1, keepdims=True), 1e-9)
         if dtype == "bf16" and "gn_bf16" not in prep:

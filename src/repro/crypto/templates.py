@@ -44,12 +44,16 @@ class KeyedRotation:
         # fix signs so Q is unique given the seed (deterministic re-keying)
         return q * jnp.sign(jnp.diag(r))[None, :]
 
+    # the inner-product preservation above holds only at full f32: the
+    # default matmul precision on TPU is a single bf16 pass
     def protect(self, t: jax.Array) -> jax.Array:
         """t: (..., dim) raw templates -> protected templates."""
-        return jnp.einsum("...d,de->...e", t.astype(jnp.float32), self._q())
+        return jnp.einsum("...d,de->...e", t.astype(jnp.float32), self._q(),
+                          precision=jax.lax.Precision.HIGHEST)
 
     def unprotect(self, tp: jax.Array) -> jax.Array:
-        return jnp.einsum("...e,de->...d", tp.astype(jnp.float32), self._q())
+        return jnp.einsum("...e,de->...d", tp.astype(jnp.float32), self._q(),
+                          precision=jax.lax.Precision.HIGHEST)
 
 
 def cosine_scores(queries: jax.Array, gallery: jax.Array) -> jax.Array:

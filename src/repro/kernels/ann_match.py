@@ -50,7 +50,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.gallery_match import (NEG, dequantize_gallery,
                                          gallery_match_pallas,
                                          gallery_match_quant_pallas,
-                                         quantize_gallery)
+                                         mxu_precision, quantize_gallery)
 
 __all__ = ["NEG", "CellLayout", "kmeans_lite", "assign_cells",
            "build_cell_layout", "centroid_topc_pallas",
@@ -107,9 +107,10 @@ def _rescore_kernel(ids_ref, lens_ref, q_ref, cell_ref, *rest, k: int,
     g = cell_ref[...].astype(jnp.float32)            # (L, D) one cell tile
     s = jax.lax.dot_general(
         q, g, (((1,), (1,)), ((), ())),
+        precision=mxu_precision(cell_ref.dtype),
         preferred_element_type=jnp.float32)          # (1, L)
     if quantized:
-        s = s * scale_ref[...][:, 0][None, :]        # per-row dequant
+        s = s * scale_ref[...]                       # (1, L) per-row dequant
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(row < n_valid, s, NEG)             # pad rows + dead probes
     pos = jnp.where(row < n_valid,
@@ -125,10 +126,10 @@ def _rescore_kernel(ids_ref, lens_ref, q_ref, cell_ref, *rest, k: int,
         acc_s[:, slot] = m
         # an unfilled slot (every candidate already consumed / masked)
         # carries the -1 sentinel, not a stale position
+        hit = lanes == a[:, None]                    # one lane per row
         acc_p[:, slot] = jnp.where(
-            m <= NEG / 2, -1,
-            jnp.take_along_axis(cp, a[:, None], axis=1)[:, 0])
-        cs = jnp.where(lanes == a[:, None], NEG, cs)
+            m <= NEG / 2, -1, jnp.max(jnp.where(hit, cp, -1), axis=1))
+        cs = jnp.where(hit, NEG, cs)
 
     @pl.when(j == nj - 1)
     def _flush():
@@ -170,18 +171,23 @@ def cell_rescore_pallas(q: jax.Array, cells: jax.Array,
     lens = cell_lens.astype(jnp.int32)
 
     # index maps see the prefetched scalars after the grid indices; an
-    # invalid probe (-1) clamps to tile 0 and is masked inside the kernel
+    # invalid probe (-1) clamps to tile 0 and is masked inside the kernel.
+    # Every operand gets a squeezed leading axis (query or cell), so each
+    # block's last two dims equal the array's, as Mosaic requires.
     def _cell_map(i, j, ids_ref, lens_ref):
-        return (jnp.maximum(ids_ref[i, j], 0), 0)
+        return (jnp.maximum(ids_ref[i, j], 0), 0, 0)
+
+    def _query_map(i, j, ids_ref, lens_ref):
+        return (i, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, D), lambda i, j, ids_ref, lens_ref: (i, 0)),
-        pl.BlockSpec((L, D), _cell_map),
+        pl.BlockSpec((None, 1, D), _query_map),
+        pl.BlockSpec((None, L, D), _cell_map),
     ]
-    inputs = [qp, cells]
+    inputs = [qp.reshape(Q, 1, D), cells.reshape(K, L, D)]
     if quantized:
-        in_specs.append(pl.BlockSpec((L, 1), _cell_map))
-        inputs.append(cell_scale.astype(jnp.float32).reshape(-1, 1))
+        in_specs.append(pl.BlockSpec((None, 1, L), _cell_map))
+        inputs.append(cell_scale.astype(jnp.float32).reshape(K, 1, L))
     kernel = functools.partial(_rescore_kernel, k=k, L=L,
                                fuse_norm=fuse_norm, quantized=quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -189,8 +195,8 @@ def cell_rescore_pallas(q: jax.Array, cells: jax.Array,
         grid=(Q, c),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, k), lambda i, j, ids_ref, lens_ref: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j, ids_ref, lens_ref: (i, 0)),
+            pl.BlockSpec((None, 1, k), _query_map),
+            pl.BlockSpec((None, 1, k), _query_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, k), jnp.float32),
@@ -201,14 +207,14 @@ def cell_rescore_pallas(q: jax.Array, cells: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((Q, k), jnp.float32),
-            jax.ShapeDtypeStruct((Q, k), jnp.int32),
+            jax.ShapeDtypeStruct((Q, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((Q, 1, k), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(ids, lens, *inputs)
-    return scores, pos
+    return scores.reshape(Q, k), pos.reshape(Q, k)
 
 
 # ---------------------------------------------------------------------------

@@ -23,8 +23,8 @@ Block schedule: the gallery grid dimension is sequential ("arbitrary"
 semantics) so Pallas double-buffers the (BN, D) tile fetch against the
 MXU pass.  Default BN is storage-dtype-aware (``_DEF_BN``): one tile is
 kept ~2-4 MiB at D=512 so two in-flight tiles plus the query tile fit
-VMEM — the narrower the storage dtype, the larger the tile and the fewer
-grid steps for the same gallery.
+VMEM.  The int8 scale travels as a lane-dense (1, BN) row, not a
+(BN, 1) column that would pad to 128 lanes.
 
 ``fuse_norm=True`` L2-normalizes the query tile in-kernel (queries never
 round-trip through a separate normalization op); the gallery is expected
@@ -50,13 +50,26 @@ from jax.experimental.pallas import tpu as pltpu
 NEG = -3.0e38
 
 # Storage-dtype-aware default gallery tile height: sized so one (BN, 512)
-# tile stays ~2-4 MiB and double-buffers comfortably within a 16 MiB VMEM
-# budget alongside the query tile and the (BQ, k) accumulator.
-_DEF_BN = {"float32": 2048, "bfloat16": 4096, "int8": 8192}
+# tile stays ~2-4 MiB and double-buffers within the 16 MiB scoped VMEM
+# alongside the query tile, the in-kernel f32 cast of the tile and the
+# (BQ, k+BN) merge tile.  int8 shares bf16's BN: at 8192 rows the f32
+# cast plus the merge tile overflow VMEM at D=512, Q=1024 (v5e compile).
+_DEF_BN = {"float32": 2048, "bfloat16": 4096, "int8": 4096}
 
 
 def _default_bn(g_dtype) -> int:
     return _DEF_BN.get(jnp.dtype(g_dtype).name, 512)
+
+
+def mxu_precision(storage_dtype):
+    """MXU precision for a tile stored as ``storage_dtype``.  fp32 storage
+    (the parity-oracle path) gets full-precision passes.  Otherwise the
+    default pass: bf16 tiles meet bf16 queries exactly, and an int8 tile
+    is exact in bf16 while the query's bf16 rounding stays below the
+    int8 quantization error."""
+    if jnp.dtype(storage_dtype) == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return None
 
 
 def _match_kernel(*refs, k: int, bn: int, n_gallery: int,
@@ -82,10 +95,12 @@ def _match_kernel(*refs, k: int, bn: int, n_gallery: int,
     g = g_ref[...].astype(jnp.float32)               # (BN, D)
     s = jax.lax.dot_general(
         q, g, (((1,), (1,)), ((), ())),
+        precision=mxu_precision(g_ref.dtype),
         preferred_element_type=jnp.float32)          # (BQ, BN)
     if quantized:
-        # symmetric per-row dequantization of the gallery contribution
-        s = s * gs_ref[...][:, 0][None, :]
+        # symmetric per-row dequantization: the (1, BN) scale row is
+        # lane-dense, so it broadcasts over the (BQ, BN) score block
+        s = s * gs_ref[...]
     col = j * bn + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(col < n_gallery, s, NEG)           # mask tail padding
 
@@ -97,8 +112,9 @@ def _match_kernel(*refs, k: int, bn: int, n_gallery: int,
         a = jnp.argmax(cs, axis=1)                   # (BQ,)
         m = jnp.max(cs, axis=1)
         acc_s[:, slot] = m
-        acc_i[:, slot] = jnp.take_along_axis(ci, a[:, None], axis=1)[:, 0]
-        cs = jnp.where(lanes == a[:, None], NEG, cs)
+        hit = lanes == a[:, None]                    # one lane per row
+        acc_i[:, slot] = jnp.max(jnp.where(hit, ci, -1), axis=1)
+        cs = jnp.where(hit, NEG, cs)
 
     @pl.when(j == nj - 1)
     def _flush():
@@ -127,10 +143,10 @@ def _launch(q, g, g_scale, *, k: int, bq: int, bn, fuse_norm: bool,
         pl.BlockSpec((bn, D), lambda i, j: (j, 0)),
     ]
     if quantized:
-        gsp = jnp.pad(g_scale.astype(jnp.float32).reshape(-1, 1),
-                      ((0, Np - N), (0, 0)))
+        gsp = jnp.pad(g_scale.astype(jnp.float32).reshape(1, -1),
+                      ((0, 0), (0, Np - N)))
         inputs.append(gsp)
-        in_specs.append(pl.BlockSpec((bn, 1), lambda i, j: (j, 0)))
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j: (0, j)))
     kernel = functools.partial(_match_kernel, k=k_eff, bn=bn, n_gallery=N,
                                fuse_norm=fuse_norm, quantized=quantized)
     scores, idx = pl.pallas_call(
@@ -149,7 +165,7 @@ def _launch(q, g, g_scale, *, k: int, bq: int, bn, fuse_norm: bool,
             pltpu.VMEM((bq, k_eff), jnp.float32),
             pltpu.VMEM((bq, k_eff), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*inputs)

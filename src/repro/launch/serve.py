@@ -15,15 +15,17 @@ CR-FIQA/FaceNet bitstreams), and serves it three ways:
   hot-swap (the pre-fleet behaviour, unchanged).
 * ``--mode lm``: batch LM serving (prefill + decode) for the
   transformer archs.
+
+JAX picks its default backend: the TPU where one is attached.  A CPU run
+says so itself with ``JAX_PLATFORMS=cpu`` (Pallas kernels then run in
+interpret mode).
 """
 from __future__ import annotations
 
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")  # no TPU probing on CPU hosts
-
 import argparse
+import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -268,16 +270,16 @@ FLEET_TENANTS = (
 FLEET_LOAD = {"field_ops": 0.2, "recon": 0.6, "backfill": 1.2}
 
 
-def run_fleet(duration_s=3.0, load=None, hotswap=False):
-    """The canonical fleet-serving entry point: the biometric pipeline
-    behind the multi-tenant front door.  Each tenant enrolls its own
-    watchlist (tenant-scoped gallery views) and streams frames at its
-    offered rate; the door does weighted-fair admission with
-    lowest-class shed, and the run prints the per-tenant ledger."""
-    reg, gallery = build_biometric_pipeline(tenant_scoped=True)
+def build_fleet(seed=0, n_shards=1):
+    """The fleet's pipeline with every tenant's own watchlist enrolled.
+
+    Tenant ``i``'s subjects are frames ``[10*i, 10*i+10)`` of the shared
+    frame bank, embedded by the same det->quality->embed path the
+    streamed frames take.  Returns ``(reg, gallery, src, tenant_base)``;
+    ``tenant_base[name]`` is the tenant's first frame."""
+    reg, gallery = build_biometric_pipeline(seed=seed, n_shards=n_shards,
+                                            tenant_scoped=True)
     src = FrameStream(seed=3)
-    # disjoint per-tenant watchlists from the shared frame bank: tenant
-    # i's subjects are frames [10*i, 10*i+10)
     tenant_base = {}
     for i, t in enumerate(FLEET_TENANTS):
         base = 10 * i
@@ -285,7 +287,14 @@ def run_fleet(duration_s=3.0, load=None, hotswap=False):
         gallery.enroll(_pipeline_embed(reg, src, range(base, base + 10)),
                        [f"{t.name}/subject{j}" for j in range(10)],
                        tenant=t.name)
+    return reg, gallery, src, tenant_base
 
+
+def serve_fleet(reg, src, tenant_base, duration_s=3.0, load=None,
+                hotswap=False):
+    """Serve ``duration_s`` seconds of tenant traffic through the front
+    door and the engine; each tenant streams its own subjects' frames
+    at its offered rate.  Returns the ``EngineReport``."""
     fd = FrontDoor()
     for t in FLEET_TENANTS:
         fd.add_tenant(t)
@@ -305,7 +314,17 @@ def run_fleet(duration_s=3.0, load=None, hotswap=False):
                 src.frame_at(b + i % 10)))
     if hotswap:
         eng.schedule_remove(1.0, slot=1)
-    rep = eng.run(until=float("inf"))
+    return eng.run(until=float("inf"))
+
+
+def run_fleet(duration_s=3.0, load=None, hotswap=False):
+    """The canonical fleet-serving entry point: the biometric pipeline
+    behind the multi-tenant front door.  Each tenant enrolls its own
+    watchlist (tenant-scoped gallery views) and streams frames at its
+    offered rate; the door does weighted-fair admission with
+    lowest-class shed, and the run prints the per-tenant ledger."""
+    reg, _, src, tenant_base = build_fleet()
+    rep = serve_fleet(reg, src, tenant_base, duration_s, load, hotswap)
     wl = reg.slots[3].cartridge.stats
     fdd = rep.frontdoor
     print(f"[serve-fleet] frames={rep.frames_out}/{rep.frames_in} "
@@ -358,9 +377,27 @@ def run_lm(arch="tinyllama-1.1b", batch=2, prompt_len=32, gen=16):
     dt = time.time() - t0
     toks = jnp.concatenate(outs, axis=1)
     print(f"[serve-lm] {arch}: generated {gen}x{batch} tokens "
-          f"({batch * (gen - 1) / dt:.1f} tok/s on CPU); "
+          f"({batch * (gen - 1) / dt:.1f} tok/s on "
+          f"{jax.default_backend()}); "
           f"sample: {np.asarray(toks[0])[:12]}")
     return toks
+
+
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache():
+    """Turn on JAX's persistent compile cache for this process.  Called at
+    start-up by the entry points, never on import.  ``JAX_COMPILATION_
+    CACHE_DIR``, when set, is read by JAX itself and wins; otherwise the
+    cache lives at ``<checkout>/.jax_cache``, a fixed path, because the
+    directory is part of what a later run must find again.  Every
+    compile is kept (the kernels compile in well under JAX's default
+    one-second floor)."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(CHECKOUT / ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 def main(argv=None):
@@ -373,6 +410,7 @@ def main(argv=None):
                     help="fleet mode: seconds of offered traffic")
     ap.add_argument("--no-hotswap", action="store_true")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.mode == "fleet":
         run_fleet(args.duration)
     elif args.mode == "biometric":

@@ -25,6 +25,23 @@ def test_rotation_preserves_cosine_exactly():
                                atol=2e-5)
 
 
+def test_rotation_pins_full_precision():
+    """The rotation must not run at the backend's default matmul
+    precision (one bf16 pass on TPU): both directions pin HIGHEST, and
+    protected cosines match raw ones to 1e-5."""
+    rot = KeyedRotation(128, seed=5)
+    t = jax.random.normal(jax.random.PRNGKey(4), (32, 128))
+    for f in (rot.protect, rot.unprotect):
+        dots = [e for e in jax.make_jaxpr(f)(t).jaxpr.eqns
+                if e.primitive.name == "dot_general"]
+        assert dots and all(
+            e.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2
+            for e in dots), dots
+    raw = np.asarray(cosine_scores(t, t))
+    prot = np.asarray(cosine_scores(rot.protect(t), rot.protect(t)))
+    np.testing.assert_allclose(prot, raw, atol=1e-5)
+
+
 def test_rotation_hides_templates():
     """Protected template far from raw (rotation is not near-identity)."""
     rot = KeyedRotation(64, seed=9)

@@ -86,7 +86,6 @@ with use_rules(rules, mesh):
                 donate_argnums=(0, 1)).lower(
         params, ost, batch, jax.ShapeDtypeStruct((), jnp.int32)).compile()
 ca = c.cost_analysis()
-ca = ca[0] if isinstance(ca, (list, tuple)) else ca   # jax version compat
 assert ca.get("flops", 0) > 0
 dec = sp.input_specs(cfg, cb.ShapeSpec("d", 128, 8, "decode"), mesh, rules)
 with use_rules(rules, mesh):
