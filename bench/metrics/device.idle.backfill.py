@@ -1,0 +1,6 @@
+"""Device: 1 less the union of device-operation intervals over the traced
+window (%)."""
+
+
+def read(view):
+    return view.device_idle()
