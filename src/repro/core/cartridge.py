@@ -17,8 +17,10 @@ from typing import Any, Callable, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import messages as msg
+from repro.core import spans
 
 
 @dataclass
@@ -71,7 +73,7 @@ class Cartridge:
         self._fn = None
         self._loaded = False
         self._clone_seq = 0
-        self.stats = {"processed": 0, "busy_s": 0.0}
+        self.stats = {"processed": 0}
 
     # -- lifecycle ----------------------------------------------------------
     def load(self) -> float:
@@ -121,7 +123,7 @@ class Cartridge:
         """
         self._clone_seq += 1
         rep = copy.copy(self)
-        rep.stats = {"processed": 0, "busy_s": 0.0}
+        rep.stats = {"processed": 0}
         rep._clone_seq = 0             # the replica numbers its own clones
         rep.name = name or f"{self.name}#r{self._clone_seq}"
         rep.device = copy.copy(device if device is not None else self.device)
@@ -133,9 +135,10 @@ class Cartridge:
 
     def process(self, m: msg.Message) -> msg.Message:
         assert self._loaded, f"{self.name}: process() before load()"
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(self._fn(self.params, m.payload))
-        self.stats["busy_s"] += time.perf_counter() - t0
+        with TraceAnnotation(spans.CARTRIDGE_CALL):
+            out = self._fn(self.params, m.payload)
+        with TraceAnnotation(spans.CARTRIDGE_SYNC):
+            out = jax.block_until_ready(out)
         self.stats["processed"] += 1
         return m.with_payload(out, self.produces.kind)
 

@@ -43,7 +43,9 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro.core import spans
 from repro.crypto.templates import (KeyedRotation, decrypt_array,
                                     encrypt_array)
 
@@ -423,74 +425,89 @@ class SecureGallery:
         another's match).  ``tenant=None`` searches the whole gallery —
         the fleet-operator view, and the pre-tenancy behaviour.
         """
-        assert self._n > 0, "empty gallery"
-        dtype = dtype or self.match_dtype
-        if dtype not in MATCH_DTYPES:
-            raise ValueError(f"dtype must be one of {MATCH_DTYPES}")
-        if mode not in MATCH_MODES:
-            raise ValueError(f"mode must be one of {MATCH_MODES}")
-        if mode == "ann" and not self.ann_indexed:
-            raise ValueError("ANN index not built — call "
-                             "build_ann_index() before match(mode='ann')")
-        code = None
-        n_scope = self._n
-        if tenant is not None:
-            code = self._tenant_code(tenant)
-            n_scope = int((self._tenant_tags == code).sum())
-            if n_scope == 0:
-                raise ValueError(f"tenant {tenant!r} has no enrolled rows")
-        k = min(k, n_scope)
-        q = self.rotation.protect(jnp.asarray(raw_queries))
+        # host spans tiling the call (repro.core.spans)
+        with TraceAnnotation(spans.MATCH_SCOPE):
+            assert self._n > 0, "empty gallery"
+            dtype = dtype or self.match_dtype
+            if dtype not in MATCH_DTYPES:
+                raise ValueError(f"dtype must be one of {MATCH_DTYPES}")
+            if mode not in MATCH_MODES:
+                raise ValueError(f"mode must be one of {MATCH_MODES}")
+            if mode == "ann" and not self.ann_indexed:
+                raise ValueError("ANN index not built — call "
+                                 "build_ann_index() before match(mode='ann')")
+            code = None
+            n_scope = self._n
+            if tenant is not None:
+                code = self._tenant_code(tenant)
+                n_scope = int((self._tenant_tags == code).sum())
+                if n_scope == 0:
+                    raise ValueError(f"tenant {tenant!r} has no enrolled "
+                                     "rows")
+            k = min(k, n_scope)
+        with TraceAnnotation(spans.MATCH_PROTECT):
+            q = self.rotation.protect(jnp.asarray(raw_queries))
         centroid_rows = 0
         cell_rows = 0
         if mode == "ann":
-            nprobe = max(1, min(nprobe, self._ann_n_cells))
-            _, cell_ids = self._coarse_scan(q, nprobe, dtype)
-            centroid_rows = self._ann_n_cells
+            with TraceAnnotation(spans.MATCH_SCAN):
+                nprobe = max(1, min(nprobe, self._ann_n_cells))
+                _, cell_ids = self._coarse_scan(q, nprobe, dtype)
+                centroid_rows = self._ann_n_cells
         shard_scores, shard_gids = [], []
         for s in range(self.n_shards):
-            rows = None
-            n_s = len(self._shard_ids[s])
-            if code is not None and n_s:
-                rows = self._tenant_shard_rows(s, code)
-                n_s = len(rows)
-            if n_s == 0:
-                continue
-            ks = min(k, n_s)
-            if mode == "ann":
-                scores, gids, scored = self._match_shard_ann(
-                    s, q, cell_ids, ks, dtype, code)
-                cell_rows += scored
-            else:
-                scores, idx = self._match_shard(s, q, ks, dtype, rows)
-                scores = np.asarray(scores)
-                gids = self._shard_ids[s][np.asarray(idx)]
-                cell_rows += n_s          # exact: the whole scope scored
-            shard_scores.append(scores)
-            shard_gids.append(gids)
-        all_s = np.concatenate(shard_scores, axis=1)       # (Q, sum ks)
-        all_g = np.concatenate(shard_gids, axis=1)
+            with TraceAnnotation(spans.MATCH_SCAN) as span:
+                rows = None
+                n_s = len(self._shard_ids[s])
+                if code is not None and n_s:
+                    rows = self._tenant_shard_rows(s, code)
+                    n_s = len(rows)
+                if rows is not None and mode == "exact":
+                    span.set_metadata(index_bytes=rows.nbytes)
+                if n_s == 0:
+                    continue
+                ks = min(k, n_s)
+                if mode == "ann":
+                    scores, gids, scored = self._match_shard_ann(
+                        s, q, cell_ids, ks, dtype, code)
+                    cell_rows += scored
+                else:
+                    scores, idx = self._match_shard(s, q, ks, dtype, rows)
+                    scores = np.asarray(scores)
+                    gids = self._shard_ids[s][np.asarray(idx)]
+                    cell_rows += n_s      # exact: the whole scope scored
+                shard_scores.append(scores)
+                shard_gids.append(gids)
         if len(shard_scores) > 1 or mode == "ann":         # top-k merge
-            # primary key: score desc; tie-break: global id asc — equal
-            # scores order identically for every reshard() topology
-            # (sentinel slots sink: NEG scores with id -1)
-            sort_g = np.where(all_g < 0, np.iinfo(np.int64).max, all_g)
-            top = np.lexsort((sort_g, -all_s), axis=1)[:, :k]
-            all_s = np.take_along_axis(all_s, top, axis=1)
-            all_g = np.take_along_axis(all_g, top, axis=1)
-        self.last_match_stats = {
-            "mode": mode, "dtype": dtype, "rows_total": self._n,
-            "centroid_rows": centroid_rows, "cell_rows": cell_rows,
-            "rows_scored": centroid_rows + cell_rows,
-            "scan_fraction": (centroid_rows + cell_rows) / self._n,
-        }
-        if tenant is not None:
-            self.last_match_stats["tenant"] = tenant
-            self.last_match_stats["tenant_rows"] = n_scope
+            with TraceAnnotation(spans.MATCH_SCAN):
+                all_s = np.concatenate(shard_scores, axis=1)  # (Q, sum ks)
+                all_g = np.concatenate(shard_gids, axis=1)
+                # primary key: score desc; tie-break: global id asc —
+                # equal scores order identically for every reshard()
+                # topology (sentinel slots sink: NEG scores with id -1)
+                sort_g = np.where(all_g < 0, np.iinfo(np.int64).max, all_g)
+                top = np.lexsort((sort_g, -all_s), axis=1)[:, :k]
+                all_s = np.take_along_axis(all_s, top, axis=1)
+                all_g = np.take_along_axis(all_g, top, axis=1)
+        else:
+            all_s, all_g = shard_scores[0], shard_gids[0]
+        with TraceAnnotation(spans.MATCH_RESULTS, labels=len(self._labels)):
+            self.last_match_stats = {
+                "mode": mode, "dtype": dtype, "rows_total": self._n,
+                "centroid_rows": centroid_rows, "cell_rows": cell_rows,
+                "rows_scored": centroid_rows + cell_rows,
+                "scan_fraction": (centroid_rows + cell_rows) / self._n,
+            }
+            if tenant is not None:
+                self.last_match_stats["tenant"] = tenant
+                self.last_match_stats["tenant_rows"] = n_scope
+            return self._labels_of(all_g), jnp.asarray(all_s)
+
+    def _labels_of(self, gids: np.ndarray) -> np.ndarray:
+        """Labels of global row ids, None for -1 slots.  The label array
+        it builds is freed when it returns, inside the caller's span."""
         label_arr = np.asarray(self._labels, object)
-        labels = np.where(all_g >= 0, label_arr[np.clip(all_g, 0, None)],
-                          None)
-        return labels, jnp.asarray(all_s)
+        return np.where(gids >= 0, label_arr[np.clip(gids, 0, None)], None)
 
     # -- topology ----------------------------------------------------------------
     def failover_shard(self, dead: int, into: Optional[int] = None) -> int:

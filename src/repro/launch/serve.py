@@ -30,9 +30,11 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.bus import BusParams, SharedBus, calibrated
 from repro.core import messages as msg
+from repro.core import spans
 from repro.core.cartridge import Cartridge, DeviceModel, FnCartridge
 from repro.crypto import SecureGallery
 from repro.data import FrameStream
@@ -160,38 +162,48 @@ class WatchlistCartridge(Cartridge):
         return tenant
 
     def process_batch(self, ms):
-        live = [m for m in ms if m.payload is not None]
-        if not live:
-            return ms
-        # one gallery.match kernel dispatch per tenant scope in the
-        # micro-batch (a single call when not tenant-scoped)
-        groups: dict = {}
-        for i, m in enumerate(live):
-            groups.setdefault(self._scope_of(m), []).append(i)
-        labels = [None] * len(live)
-        scores = [0.0] * len(live)
+        with TraceAnnotation(spans.MATCH_BATCH):
+            return self._match_batch(ms)
+
+    def _match_batch(self, ms):
+        # host spans: scope, then per group the gallery's own phases,
+        # then results (repro.core.spans)
+        with TraceAnnotation(spans.MATCH_SCOPE):
+            live = [m for m in ms if m.payload is not None]
+            if not live:
+                return ms
+            # one gallery.match kernel dispatch per tenant scope in the
+            # micro-batch (a single call when not tenant-scoped)
+            groups: dict = {}
+            for i, m in enumerate(live):
+                groups.setdefault(self._scope_of(m), []).append(i)
+            labels = [None] * len(live)
+            scores = [0.0] * len(live)
         for tenant, idxs in groups.items():
-            q = np.stack([np.asarray(live[i].payload) for i in idxs])
+            with TraceAnnotation(spans.MATCH_SCOPE):
+                q = np.stack([np.asarray(live[i].payload) for i in idxs])
             lab, sc = self.gallery.match(q, k=1, mode=self.mode,
                                          nprobe=self.nprobe, tenant=tenant)
-            sc = np.asarray(sc)
-            self.stats["match_calls"] += 1
-            for j, i in enumerate(idxs):
-                labels[i] = lab[j, 0]
-                scores[i] = float(sc[j, 0])
-        self.stats["hits"] += sum(1 for s in scores
-                                  if s >= self.hit_threshold)
-        self.stats["processed"] += len(live)
-        results = iter(zip(labels, scores))
-        out = []
-        for m in ms:
-            if m.payload is None:
-                out.append(m)
-            else:
-                lab, sc = next(results)
-                out.append(m.with_payload({"label": lab, "score": sc},
-                                          msg.MATCH_RESULT))
-        return out
+            with TraceAnnotation(spans.MATCH_RESULTS):
+                sc = np.asarray(sc)
+                self.stats["match_calls"] += 1
+                for j, i in enumerate(idxs):
+                    labels[i] = lab[j, 0]
+                    scores[i] = float(sc[j, 0])
+        with TraceAnnotation(spans.MATCH_RESULTS):
+            self.stats["hits"] += sum(1 for s in scores
+                                      if s >= self.hit_threshold)
+            self.stats["processed"] += len(live)
+            results = iter(zip(labels, scores))
+            out = []
+            for m in ms:
+                if m.payload is None:
+                    out.append(m)
+                else:
+                    lab, sc = next(results)
+                    out.append(m.with_payload({"label": lab, "score": sc},
+                                              msg.MATCH_RESULT))
+            return out
 
     def load(self):
         self._loaded = True

@@ -43,6 +43,14 @@ namespaced, stable-name snapshot (``engine.frames.out``,
 ``gallery.match.rows_scored``, ...) unifying the stats surfaces that
 previously lived in six different dicts.  ``EngineReport.metrics()``
 builds it; ``ingest()`` merges any component's dict under a prefix.
+
+Host spans.  The recorder above is the engine's *virtual-time* record.
+The chip path (the cartridges' own ``process_batch``, driven straight
+from a serving loop) records on the device trace's clock instead, in
+``jax.profiler.TraceAnnotation`` spans inside the watchlist match and the
+stage calls.  Their names, and what each covers, are kept in
+``repro.core.spans``: ``core`` and ``crypto`` mark them, and importing
+this package from there would load the engine.
 """
 from __future__ import annotations
 
