@@ -17,8 +17,8 @@ code.  The arguments named below go into the trace as event stats.
   scan and one for a top-k merge across shards; arg ``index_bytes`` on a
   shard's span when a host row index goes with a tenant subset.
 - ``match.results`` — answers back to the caller: match stats, the label
-  lookup, scores, the cartridge's result messages; arg ``labels`` (size of
-  the label array built) on the gallery's span.
+  lookup, scores, the cartridge's result messages; arg ``labels`` (the
+  labels the call looked up, queries times k) on the gallery's span.
 - ``cartridge.call`` / ``cartridge.sync`` — ``Cartridge.process``: the
   host's dispatch of one stage call, then its wait for the result.
 """

@@ -491,7 +491,7 @@ class SecureGallery:
                 all_g = np.take_along_axis(all_g, top, axis=1)
         else:
             all_s, all_g = shard_scores[0], shard_gids[0]
-        with TraceAnnotation(spans.MATCH_RESULTS, labels=len(self._labels)):
+        with TraceAnnotation(spans.MATCH_RESULTS, labels=all_g.size):
             self.last_match_stats = {
                 "mode": mode, "dtype": dtype, "rows_total": self._n,
                 "centroid_rows": centroid_rows, "cell_rows": cell_rows,
@@ -504,10 +504,15 @@ class SecureGallery:
             return self._labels_of(all_g), jnp.asarray(all_s)
 
     def _labels_of(self, gids: np.ndarray) -> np.ndarray:
-        """Labels of global row ids, None for -1 slots.  The label array
-        it builds is freed when it returns, inside the caller's span."""
-        label_arr = np.asarray(self._labels, object)
-        return np.where(gids >= 0, label_arr[np.clip(gids, 0, None)], None)
+        """Labels of global row ids, None for -1 slots.  Only the ids
+        asked for are looked up, one by one, so a label that is itself a
+        sequence comes back as the object enrolled."""
+        out = np.full(gids.shape, None, object)
+        flat = out.reshape(-1)
+        for j, g in enumerate(gids.reshape(-1).tolist()):
+            if g >= 0:
+                flat[j] = self._labels[g]
+        return out
 
     # -- topology ----------------------------------------------------------------
     def failover_shard(self, dead: int, into: Optional[int] = None) -> int:

@@ -220,6 +220,72 @@ def test_int8_recall_at_1_on_noisy_queries():
     assert np.mean(got == truth) >= 0.99
 
 
+# (mode, dtype, shards, tenant-scoped, nprobe, k, tuple labels)
+LABEL_CASES = [
+    ("exact", "fp32", 1, False, 8, 3, False),
+    ("exact", "bf16", 2, False, 8, 3, False),
+    ("exact", "int8", 1, True, 8, 3, False),
+    ("exact", "fp32", 2, True, 8, 3, False),
+    ("ann", "fp32", 1, False, 4, 3, False),
+    ("ann", "bf16", 2, True, 4, 3, False),
+    ("ann", "int8", 2, False, 4, 3, False),
+    ("ann", "int8", 1, True, 1, 40, False),      # probed cells run short
+    ("ann", "fp32", 2, False, 1, 40, False),
+    ("exact", "int8", 2, True, 8, 3, True),
+    ("ann", "bf16", 2, False, 1, 40, True),
+]
+
+
+@pytest.mark.parametrize(
+    "mode,dtype,shards,scoped,nprobe,k,tuples", LABEL_CASES,
+    ids=["-".join(map(str, c)) for c in LABEL_CASES])
+def test_match_labels_are_the_enrolled_labels_of_the_merged_ids(
+        monkeypatch, mode, dtype, shards, scoped, nprobe, k, tuples):
+    """The labels ``match`` returns are those of the global ids the merge
+    kept, read from an object array of every label built here, with None
+    in -1 slots; a tuple label comes back as the very object enrolled."""
+    rng = np.random.default_rng(15)
+    dim, per = 32, 120
+    store = SecureGallery(dim, seed=4, n_shards=shards, match_dtype=dtype)
+    tenants = ("a", "b") if scoped else (None,)
+    labels, rows = [], []
+    for t in tenants:
+        g = rng.normal(size=(per, dim)).astype(np.float32)
+        lab = [(str(t), i) if tuples else f"{t}-{i}" for i in range(per)]
+        store.enroll(g, lab, tenant=t)
+        labels += lab
+        rows.append(g)
+    if mode == "ann":
+        store.build_ann_index(n_cells=16)
+    rows = np.concatenate(rows)
+    tenant = tenants[-1]              # the last tenant, or the shared pool
+    want_gid = per * (len(tenants) - 1) + np.arange(0, per, 23)[:5]
+    q = rows[want_gid] + 0.02 * rng.normal(size=(5, dim)).astype(np.float32)
+
+    seen = []
+    lookup = store._labels_of
+
+    def spy(gids):
+        seen.append(np.array(gids))
+        return lookup(gids)
+
+    monkeypatch.setattr(store, "_labels_of", spy)
+    got, _ = store.match(q, k=k, mode=mode, nprobe=nprobe, tenant=tenant)
+
+    gids, = seen
+    every = np.fromiter(labels, object, count=len(labels))
+    ref = np.where(gids >= 0, every[np.clip(gids, 0, None)], None)
+    assert got.shape == ref.shape == (len(q), k)
+    assert got.tolist() == ref.tolist()
+    assert [got[i, 0] for i in range(len(q))] == list(every[want_gid])
+    if nprobe == 1:
+        assert (gids < 0).any() and all(
+            got[i, j] is None for i, j in zip(*np.nonzero(gids < 0)))
+    if tuples:
+        assert all(got[i, j] is labels[gids[i, j]]
+                   for i, j in zip(*np.nonzero(gids >= 0)))
+
+
 # ---------------------------------------------------------------------------
 # engine event core
 # ---------------------------------------------------------------------------

@@ -105,13 +105,13 @@ def test_watchlist_batch_spans_tile_the_call_in_order(tmp_path):
     assert sum(e - s for s, e, _, _ in phases) > 0.9 * (b1 - b0)
 
     # arguments only where a metric reads them: the row index sent with a
-    # tenant subset, and the size of the gallery's label array
+    # tenant subset, and the labels the gallery looked up (probes x k=1)
     g = wl.gallery
     args = [{} for _ in phases]
-    for scan, tenant in ((4, "checkpoint"), (10, "recon")):
+    for scan, tenant, probes in ((4, "checkpoint", 3), (10, "recon", 2)):
         rows = g._tenant_shard_rows(0, g._tenant_code(tenant))
         args[scan] = {"index_bytes": rows.nbytes}
-        args[scan + 1] = {"labels": 80}
+        args[scan + 1] = {"labels": probes}
     assert [p[3] for p in phases] == args
     assert [m.payload["label"] for m in out] == \
         ["checkpoint-0", "recon-1", "checkpoint-2", "recon-3", "checkpoint-4"]
@@ -128,7 +128,13 @@ def test_shared_pool_and_shards_add_a_merge_span(tmp_path):
     assert scans[0][1] <= scans[1][0] and scans[1][1] <= scans[2][0]
     assert len(spans[sp.MATCH_PROTECT]) == 1
     assert [st for _, _, st in spans[sp.MATCH_RESULTS]] == \
-        [{"labels": 80}, {}, {}]
+        [{"labels": 5}, {}, {}]
+    # a direct call at k > 1 looks up k labels per query
+    q = np.stack([m.payload for m in ms])
+    (labels, _), spans = _traced(tmp_path / "k4",
+                                 lambda: wl.gallery.match(q, k=4))
+    assert labels.shape == (5, 4)
+    assert [st for _, _, st in spans[sp.MATCH_RESULTS]] == [{"labels": 20}]
 
 
 def test_stage_call_and_sync_spans_per_frame(tmp_path):
