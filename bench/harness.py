@@ -14,14 +14,16 @@ door run on a virtual clock, so they stay out of the window.
 Everything that belongs to one configuration, one mix or one per-layer
 metric is a file found by its name: ``configs/<config>.json``,
 ``traffic/<mix>.json``, ``metrics/<metric>.py`` and ``stages/<stage>.py``.
+A configuration's ``stages`` and ``dim`` name the chain the program
+builds (``build_biometric``) as well as the reference's.
 """
 from __future__ import annotations
 
 import contextlib
-import faulthandler
 import functools
 import gc
 import importlib.util
+import inspect
 import json
 import re
 import resource
@@ -42,7 +44,6 @@ BENCH = Path(__file__).resolve().parent
 CHECKOUT = BENCH.parent
 METRIC_DIR = BENCH / "metrics"
 ROW_BLOCK = 131072                 # gallery rows generated per device call
-STALL_S = 0.8                      # a window cycle this long prints stacks
 
 
 def derive(seed: int, *stream: int) -> int:
@@ -108,17 +109,6 @@ def probes_near(rows: np.ndarray, idx: np.ndarray, cos: float,
     return rows[idx] + np.float32(np.sqrt(1.0 / cos ** 2 - 1.0)) * noise
 
 
-@contextlib.contextmanager
-def stall_watch(seconds: float = STALL_S):
-    """Print every thread's Python stack to standard error if the block
-    runs longer than ``seconds``: where the host was during a stall."""
-    faulthandler.dump_traceback_later(seconds, file=sys.__stderr__)
-    try:
-        yield
-    finally:
-        faulthandler.cancel_dump_traceback_later()
-
-
 def host_cpu_s() -> float:
     """This process's CPU seconds so far, all threads: over a slow cycle,
     near its wall time says the host computed, far below it says the
@@ -144,6 +134,69 @@ class GcPauses:
     def summary(self) -> dict:
         return {g: (len(p), sum(p), max(p, default=0.0))
                 for g, p in self.pauses.items()}
+
+
+def build_biometric(serve, cfg: dict, seed: int, tenant_scoped: bool):
+    """The program's biometric chain for ``cfg``, from the program's own
+    builder: ``(chain, gallery)``.
+
+    The configuration names the chain (``stages``: each a ``name`` and
+    the ``key`` of ``split(PRNGKey(seed), 4)`` its weights come from) and
+    the embedding width (``dim``).  A builder that takes ``stages=`` and
+    ``dim=`` is given them; one that takes neither builds its one chain,
+    stage ``i`` from key ``i``.  Either way the chain built must be the
+    one named, stage for stage, or the build fails naming the stage."""
+    kw = dict(seed=seed, n_shards=cfg.get("n_shards", 1),
+              match_dtype=cfg["match_dtype"],
+              match_mode=cfg.get("match_mode", "exact"),
+              tenant_scoped=tenant_scoped)
+    named = "stages" in inspect.signature(
+        serve.build_biometric_pipeline).parameters
+    if named:
+        kw.update(stages=cfg["stages"], dim=cfg["dim"])
+    reg, gallery = serve.build_biometric_pipeline(**kw)
+    chain = reg.chain()
+    built = [c.name for c in chain[:-1]]
+    for i, s in enumerate(cfg["stages"]):
+        if i >= len(built) or built[i] != s["name"] or (
+                not named and s["key"] != i):
+            raise ValueError(
+                f"stage {s['name']!r} (key {s['key']}) of configuration "
+                f"{cfg.get('name')!r} is not what the program builds at "
+                f"position {i}: {built}, stage i from key i")
+    if len(built) != len(cfg["stages"]) or gallery.dim != cfg["dim"]:
+        raise ValueError(f"configuration {cfg.get('name')!r} names stages "
+                         f"{[s['name'] for s in cfg['stages']]} and width "
+                         f"{cfg['dim']}; the program builds {built} and "
+                         f"width {gallery.dim}")
+    return chain, gallery
+
+
+class Reservoir:
+    """A sample of ``k`` requests, fixed by ``seed``, from a stream whose
+    length is known only at its end: each request gets a priority drawn
+    from the seed and its sequence number, and the sample is the ``k``
+    lowest so far.  The sample of ``n`` requests depends on the seed and
+    ``n`` alone."""
+
+    def __init__(self, k: int, seed: int):
+        self.k, self.seed = k, seed
+        self.pri = np.empty(0)
+        self.seq = np.empty(0, np.int64)
+
+    def offer(self, base: int, n: int) -> set:
+        """Offer requests ``base`` to ``base + n - 1``; returns those of
+        them now in the sample."""
+        p = np.random.default_rng(
+            np.random.SeedSequence([self.seed, base])).random(n)
+        pri = np.concatenate([self.pri, p])
+        seq = np.concatenate([self.seq, base + np.arange(n)])
+        low = np.argsort(pri, kind="stable")[: self.k]
+        self.pri, self.seq = pri[low], seq[low]
+        return set(self.seq[self.seq >= base].tolist())
+
+    def sample(self) -> list:
+        return sorted(self.seq.tolist())
 
 
 class Scope:
@@ -181,12 +234,8 @@ class Cell:
         self.tenants = tenants
         prog_seed = derive(self.seed, 0)
         if cfg["pipeline"] == "biometric":
-            reg, gallery = serve.build_biometric_pipeline(
-                seed=prog_seed, n_shards=cfg.get("n_shards", 1),
-                match_dtype=cfg["match_dtype"],
-                match_mode=cfg.get("match_mode", "exact"),
-                tenant_scoped=bool(tenants))
-            chain = reg.chain()
+            chain, gallery = build_biometric(serve, cfg, prog_seed,
+                                             bool(tenants))
         else:
             gallery = SecureGallery(cfg["dim"], n_shards=cfg.get("n_shards", 1),
                                     match_dtype=cfg["match_dtype"])
@@ -208,20 +257,20 @@ class Cell:
         n, d = cfg["n_templates"], cfg["dim"]
         frame_seed = derive(self.seed, 1)
         self.frame_seed = frame_seed
-        if not self.tenants:
-            self.scopes = [Scope(None, gaussian_rows(derive(self.seed, 2),
-                                                     n, d))]
-        else:
-            planted = cfg["planted_per_tenant"]
-            rest = gaussian_rows(derive(self.seed, 2),
-                                 n - planted * len(self.tenants), d)
-            self.scopes = []
-            for t, (name, rows) in enumerate(zip(
-                    self.tenants, np.array_split(rest, len(self.tenants)))):
+        # one scope per tenant, or one shared scope; ``planted_per_tenant``
+        # subjects, embedded by the reference stages, head each scope
+        names = self.tenants or [None]
+        planted = cfg.get("planted_per_tenant", 0)
+        rest = gaussian_rows(derive(self.seed, 2), n - planted * len(names),
+                             d)
+        self.scopes = []
+        for t, (name, rows) in enumerate(zip(
+                names, np.array_split(rest, len(names)))):
+            if planted:
                 subj = np.stack([self.stage_ref.embed(frames.frame_at(
                     frame_seed, t * planted + j)) for j in range(planted)])
-                self.scopes.append(Scope(name, np.concatenate(
-                    [subj.astype(np.float32), rows])))
+                rows = np.concatenate([subj.astype(np.float32), rows])
+            self.scopes.append(Scope(name, rows))
         for s in self.scopes:
             self.gallery.enroll(s.rows, s.labels(), tenant=s.tenant)
 
@@ -286,10 +335,11 @@ class Cell:
             self.probe_cfg.get("mate_share", 0.5), stream)
         return tenant, self.items(tenant, mated, pick)
 
-    def _messages(self, reqs, tenant, item):
-        """The cycle's requests as messages.  Camera frames are put on
-        the device here, in a span of their own, so that the stage spans
-        hold the stages' work and not the frames' transfer."""
+    def _messages(self, reqs, tenant, item, base=0):
+        """The cycle's requests as messages, request ``r`` numbered
+        ``base + r``.  Camera frames are put on the device here, in a
+        span of their own, so that the stage spans hold the stages' work
+        and not the frames' transfer."""
         from repro.core import messages as msg
         data = [self.bank[item[r]] for r in reqs]
         kind = msg.EMBEDDING
@@ -298,7 +348,8 @@ class Cell:
             with self._span("lane.h2d"):
                 data = jax.block_until_ready(jax.device_put(data))
         names = self.tenants or [None]
-        return [msg.Message(kind, int(r), x, {"tenant": names[tenant[r]]})
+        return [msg.Message(kind, base + int(r), x,
+                            {"tenant": names[tenant[r]]})
                 for r, x in zip(reqs, data)]
 
     def _span(self, name: str):
@@ -306,12 +357,14 @@ class Cell:
             return jax.profiler.TraceAnnotation(name)
         return contextlib.nullcontext()
 
-    def _cycle(self, reqs, tenant, item, keep=None, rec=None, times=None):
-        """One service cycle over requests ``reqs``: every stage's
-        ``process_batch`` in slot order.  Returns the watchlist stage's
-        messages; stage outputs of requests in ``keep`` go to ``rec``,
-        and each stage's seconds to ``times``."""
-        ms = self._messages(reqs, tenant, item)
+    def _cycle(self, reqs, tenant, item, keep=None, rec=None, times=None,
+                base=0):
+        """One service cycle over requests ``reqs``, numbered from
+        ``base``: every stage's ``process_batch`` in slot order.  Returns
+        the watchlist stage's messages; stage outputs of requests in
+        ``keep`` (by number) go to ``rec``, and each stage's seconds to
+        ``times``."""
+        ms = self._messages(reqs, tenant, item, base)
         for stage in self.entry_stages():
             t = time.perf_counter()
             with self._span(f"stage.{stage.name}"):
@@ -378,8 +431,7 @@ class Cell:
                     continue
                 reqs = [queue.popleft() for _ in range(min(len(queue), mb))]
                 times, used = {}, host_cpu_s()
-                with stall_watch():
-                    ms = self._cycle(reqs, tenant, item, keep, rec, times)
+                ms = self._cycle(reqs, tenant, item, keep, rec, times)
                 t = clock() - t0
                 for m in ms:
                     done[m.seq] = t
@@ -395,17 +447,28 @@ class Cell:
                 "late_ms": np.asarray(late) * 1e3}
 
     def run_closed(self, seconds: float) -> dict:
+        """Cycles of ``max_batch`` requests, numbered on through the
+        window, until ``seconds`` have passed.  For camera frames the
+        stage outputs of a seeded sample (``Reservoir``) are recorded."""
         mb = self.mix["max_batch"]
         labels, scores, tenants, items, cycles = [], [], [], [], []
+        rec, sample = {}, None
+        if self.mix["payload"] == "frames":
+            sample = Reservoir(self.mix["check_sample"],
+                               derive(self.seed, 4))
         clock = time.perf_counter
         with self._span("bench.window"):
             t0 = clock()
             c = 0
             while clock() - t0 < seconds:
                 tenant, item = self._requests(mb, c + 1)
+                keep = sample.offer(c * mb, mb) if sample else None
                 times, used = {}, host_cpu_s()
-                with stall_watch():
-                    ms = self._cycle(range(mb), tenant, item, times=times)
+                ms = self._cycle(range(mb), tenant, item, keep, rec, times,
+                                 base=c * mb)
+                if sample:
+                    for r in set(rec) - set(sample.seq.tolist()):
+                        del rec[r]
                 labels += [m.payload["label"] for m in ms]
                 scores += [m.payload["score"] for m in ms]
                 tenants.append(tenant)
@@ -417,7 +480,8 @@ class Cell:
                 "labels": labels, "scores": np.asarray(scores),
                 "tenant": np.concatenate(tenants),
                 "item": np.concatenate(items), "cycles": cycles,
-                "rec": {}, "late_ms": np.zeros(0)}
+                "keep": sample.sample() if sample else [], "rec": rec,
+                "late_ms": np.zeros(0)}
 
     def run_window(self, seconds: float) -> dict:
         """The window, with the garbage collector's pauses in it timed."""

@@ -110,6 +110,23 @@ def test_rate_is_all_answers_over_all_the_window():
     assert rate < 64 * len(out["cycles"]) / (out["wall_s"] - 0.3)
 
 
+def test_closed_loop_numbers_requests_through_the_window():
+    """Each request of a closed-loop window has its own sequence number,
+    its place among the window's answers; templates record no stage
+    outputs."""
+    seqs = []
+
+    class Seen(_Stage):
+        def process_batch(self, ms):
+            seqs.extend(m.seq for m in ms)
+            return super().process_batch(ms)
+    mix = {"loop": "closed", "max_batch": 16, "payload": "templates",
+           "check_sample": 4}
+    out = _fake_cell(mix, Seen()).run_closed(0.2)
+    assert len(out["cycles"]) > 1
+    assert seqs == list(range(out["n"])) and out["rec"] == {}
+
+
 NEW_FILES_PROBE = r"""
 import json, sys
 sys.path[:0] = [sys.argv[1], sys.argv[2]]

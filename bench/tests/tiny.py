@@ -36,12 +36,13 @@ def bench(tmp_path: Path) -> dict:
     return b
 
 
-def run_main(monkeypatch, tmp_path, capsys, argv) -> dict:
-    """``run.main`` on the CPU at the tiny size: the look for a chip is
-    skipped and the v5e peaks stand in; returns the result line."""
+def run_main(monkeypatch, tmp_path, capsys, argv, benchmark=None) -> dict:
+    """``run.main`` on the CPU at the tiny size (or over ``benchmark``):
+    the look for a chip is skipped and the v5e peaks stand in; returns
+    the result line."""
     import run
     from repro.launch import serve
-    tiny = bench(tmp_path)
+    tiny = benchmark if benchmark is not None else bench(tmp_path)
     monkeypatch.setattr(serve, "use_compile_cache", lambda: None)
     monkeypatch.setattr(harness, "load_benchmark", lambda *a: tiny)
     monkeypatch.setattr(run, "require_chips", lambda n: {
